@@ -19,6 +19,8 @@ import inspect
 import json
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 from . import (backend_bench, common, federated_scale_bench, fig2_activation,
                fig3_temperature, kernel_bench, round_engine_bench,
                serving_bench, table1_flops, table2_budgets, table3_scale,
@@ -46,6 +48,7 @@ SMOKE = ["kernels", "backend", "serving", "telemetry", "federated_scale"]
 
 
 def main(argv=None) -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(prog="benchmarks.run")
     ap.add_argument("picks", nargs="*", help=f"subset of {list(ALL)}")
     ap.add_argument("--smoke", action="store_true",

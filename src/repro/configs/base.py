@@ -58,13 +58,14 @@ class KernelConfig:
       * ``"auto"``      — fused Pallas kernels on TPU, jnp references
         elsewhere (the safe default: nothing changes on CPU);
       * ``"pallas"``    — force the Pallas kernels everywhere; off-TPU they
-        execute under the Pallas interpreter (slow, bit-faithful — the CI
-        parity configuration);
+        execute under the Pallas interpreter (slow, bit-faithful — the CPU
+        test mode);
       * ``"reference"`` — force the pure-jnp reference implementations
         everywhere, including on TPU (the debugging oracle).
 
-    ``interpret`` forces the Pallas interpreter even on TPU — the escape
-    hatch for debugging a miscompiled kernel without leaving the device.
+    ``interpret`` forces the Pallas interpreter even on TPU, for debugging
+    one kernel by hand; nothing on the chip path sets it (chip_smoke.py
+    fails if it is on).  Off the TPU the kernels interpret regardless.
     Frozen + hashable so ``ModelConfig`` stays usable as a jit static arg.
     """
 

@@ -55,9 +55,7 @@ from .flash_attention import flash_attention as _flash_pallas
 from .lora_matmul import lora_matmul as _lora_pallas
 from .lora_matmul import lora_matmul_experts as _lora_experts_pallas
 from .ops import on_tpu
-from .ragged_dispatch import ragged_combine as _ragged_combine_pallas
 from .ragged_dispatch import ragged_expert_matmul as _ragged_mm_pallas
-from .ragged_dispatch import ragged_gather as _ragged_gather_pallas
 from .topk_router import topk_router as _router_pallas
 
 _F32 = jnp.float32
@@ -279,42 +277,14 @@ def router(kcfg: KernelConfig, logits, k: int):
 
 
 # ==========================================================================
-# ragged (sort-based) MoE dispatch: gather / grouped matmul / combine
+# ragged (sort-based) MoE dispatch: grouped matmul
 # ==========================================================================
-# The three ops behind ``apply_moe(dispatch="ragged")`` — see
-# kernels/ragged_dispatch.py for the layout and docs/kernels.md for the
-# dispatch-mode trade-offs.  The plan arrays (src/valid/block_expert/rows)
-# are int32 and carry no gradient: the backward rules return ``None``
-# cotangents for them and reference-math gradients for the float operands.
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _ragged_gather_p(interpret, x, src, valid):
-    return _ragged_gather_pallas(x, src, valid, interpret=interpret)
-
-
-def _ragged_gather_fwd(interpret, x, src, valid):
-    return _ragged_gather_p(interpret, x, src, valid), (x, src, valid)
-
-
-def _ragged_gather_bwd(interpret, res, g):
-    x, src, valid = res
-    _, vjp = jax.vjp(lambda x_: ref.ragged_gather_ref(x_, src, valid), x)
-    return vjp(g)[0], None, None
-
-
-_ragged_gather_p.defvjp(_ragged_gather_fwd, _ragged_gather_bwd)
-
-
-def ragged_gather(kcfg: KernelConfig, x, src, valid):
-    """Differentiable ragged dispatch gather: x (T,D); src, valid (N,)
-    int32 -> xs (N,D) with ``xs[i] = x[src[i]] * valid[i]``.
-
-    No degenerate-shape guard needed: the grid is always N/8 (the plan
-    pads the buffer to 8-row blocks) and rows copy at full width."""
-    if use_pallas(kcfg):
-        return _ragged_gather_p(resolve_interpret(kcfg), x, src, valid)
-    return ref.ragged_gather_ref(x, src, valid)
-
+# The kernel behind ``apply_moe(dispatch="ragged")`` — see
+# kernels/ragged_dispatch.py for the layout (its gather and combine are
+# plain XLA gathers) and docs/kernels.md for the dispatch-mode trade-offs.
+# The plan's block_expert array is int32 and carries no gradient: the
+# backward rules return a ``None`` cotangent for it and reference-math
+# gradients for the float operands.
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _ragged_mm_p(scale, interpret, xs, be, w):
@@ -375,31 +345,3 @@ def ragged_expert_matmul(kcfg: KernelConfig, xs, block_expert, w,
         return _ragged_mm_lora_p(float(scale), interp, xs, block_expert,
                                  w, a, b)
     return ref.ragged_expert_matmul_ref(xs, block_expert, w, a, b, scale)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _ragged_combine_p(interpret, eo, rows, wrank):
-    return _ragged_combine_pallas(eo, rows, wrank, interpret=interpret)
-
-
-def _ragged_combine_fwd(interpret, eo, rows, wrank):
-    return _ragged_combine_p(interpret, eo, rows, wrank), (eo, rows, wrank)
-
-
-def _ragged_combine_bwd(interpret, res, g):
-    eo, rows, wrank = res
-    _, vjp = jax.vjp(
-        lambda eo_, w_: ref.ragged_combine_ref(eo_, rows, w_), eo, wrank)
-    deo, dwrank = vjp(g)
-    return deo, None, dwrank
-
-
-_ragged_combine_p.defvjp(_ragged_combine_fwd, _ragged_combine_bwd)
-
-
-def ragged_combine(kcfg: KernelConfig, eo, rows, wrank):
-    """Differentiable ragged combine: eo (N,D); rows (T,max_k) int32;
-    wrank (T,max_k) -> out (T,D) = sum_j wrank[t,j] * eo[rows[t,j]]."""
-    if use_pallas(kcfg) and not _degenerate(rows.shape[0], 8):
-        return _ragged_combine_p(resolve_interpret(kcfg), eo, rows, wrank)
-    return ref.ragged_combine_ref(eo, rows, wrank)

@@ -135,6 +135,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q, 1), jnp.float32),   # l (running sum)
             pltpu.VMEM((block_q, D), jnp.float32),   # acc
         ],
-        interpret=interpret,
+        interpret=interpret, name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(B, H, S, D)

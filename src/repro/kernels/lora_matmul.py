@@ -91,7 +91,7 @@ def lora_matmul(x: jnp.ndarray, w: jnp.ndarray, a: jnp.ndarray,
             pltpu.VMEM((bm, bn), jnp.float32),   # base accumulator
             pltpu.VMEM((bm, r), jnp.float32),    # running x·A
         ],
-        interpret=interpret,
+        interpret=interpret, name="lora_matmul",
     )(x, w, a, b)
 
 
@@ -133,5 +133,5 @@ def lora_matmul_experts(x: jnp.ndarray, w: jnp.ndarray, a: jnp.ndarray,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="lora_matmul_experts",
     )(x, w, a, b)

@@ -33,16 +33,18 @@ no capacity limit, no dropping — and each token's output depends only on
 its own row: co-batched rows provably cannot change results, which is why
 the serving engine runs this mode by default (docs/kernels.md).
 
-Three Pallas kernels implement the hot path (one grid program per
-``block_m`` row block; scalar-prefetched plan arrays drive the dynamic
-addressing), each with a pure-jnp oracle in :mod:`repro.kernels.ref` and a
-``custom_vjp`` (kernel forward, reference backward) in
-:mod:`repro.kernels.backend`:
-
-* :func:`ragged_gather`   — ``xs[i] = x[src[i]] * valid[i]``;
-* :func:`ragged_expert_matmul` — grouped (segment) LoRA matmul: row block
-  ``i`` multiplies ``w[block_expert[i]]`` (+ the LoRA bypass);
-* :func:`ragged_combine`  — ``out[t] = sum_j wrank[t,j] * eo[rows[t,j]]``.
+The row movement is XLA's own gather: :func:`ragged_gather` copies token
+rows into the buffer (``xs[i] = x[src[i]] * valid[i]``) and
+:func:`ragged_combine` sums each token's ranks back
+(``out[t] = sum_j wrank[t,j] * eo[rows[t,j]]``).  A row gather is pure
+data movement with no compute to fuse into it, so it is left to XLA, and
+both differentiate as plain jnp (their vjps are scatter-adds).  The
+compute sits in one Pallas kernel, :func:`ragged_expert_matmul`, the
+grouped (segment) LoRA matmul: row block ``i`` multiplies
+``w[block_expert[i]]`` (+ the LoRA bypass), its expert picked through the
+scalar-prefetched plan.  Its jnp oracle is
+:func:`repro.kernels.ref.ragged_expert_matmul_ref` and its ``custom_vjp``
+(kernel forward, reference backward) lives in :mod:`repro.kernels.backend`.
 """
 from __future__ import annotations
 
@@ -71,10 +73,10 @@ def ragged_rows(budget: int, num_experts: int,
 class RaggedPlan(NamedTuple):
     """Integer dispatch plan (plus the differentiable combine weights).
 
-    Built once per MoE layer call from the router outputs; consumed by the
-    three backend ops.  All layout arrays are int32 and carry no gradient;
-    ``wrank`` is the per-rank combine weight and is differentiable back to
-    the router weights."""
+    Built once per MoE layer call from the router outputs; consumed by
+    the gather, the grouped matmul and the combine.  All layout arrays are
+    int32 and carry no gradient; ``wrank`` is the per-rank combine weight
+    and is differentiable back to the router weights."""
 
     src: jnp.ndarray           # (N,)  token id per buffer row
     valid: jnp.ndarray         # (N,)  0/1 — padding rows are 0
@@ -130,38 +132,14 @@ def ragged_plan(mask: jnp.ndarray, weights: jnp.ndarray, *, budget: int,
 
 
 # ==========================================================================
-# Pallas kernels
+# gather / grouped matmul (Pallas) / combine
 # ==========================================================================
 
-def _gather_kernel(src_ref, val_ref, x_ref, o_ref, *, block_m: int):
-    i = pl.program_id(0)
-    for r in range(block_m):                       # static unroll
-        row = src_ref[i * block_m + r]
-        v = val_ref[i * block_m + r]
-        xr = pl.load(x_ref, (pl.ds(row, 1), slice(None)))
-        o_ref[r, :] = (xr * v.astype(xr.dtype))[0]
-
-
-@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
-def ragged_gather(x: jnp.ndarray, src: jnp.ndarray, valid: jnp.ndarray, *,
-                  block_m: int = BLOCK_M, interpret: bool = True):
+def ragged_gather(x: jnp.ndarray, src: jnp.ndarray,
+                  valid: jnp.ndarray) -> jnp.ndarray:
     """x: (T, D); src, valid: (N,) int32 -> xs (N, D) with
     ``xs[i] = x[src[i]] * valid[i]`` (padding rows zero)."""
-    T, D = x.shape
-    N = src.shape[0]
-    assert N % block_m == 0, (N, block_m)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(N // block_m,),
-        in_specs=[pl.BlockSpec((T, D), lambda i, s, v: (0, 0))],
-        out_specs=pl.BlockSpec((block_m, D), lambda i, s, v: (i, 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_gather_kernel, block_m=block_m),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, D), x.dtype),
-        interpret=interpret,
-    )(src, valid, x)
+    return x[src] * valid.astype(x.dtype)[:, None]
 
 
 def _matmul_kernel(be_ref, x_ref, w_ref, o_ref, *, scale: float):
@@ -227,46 +205,16 @@ def ragged_expert_matmul(xs: jnp.ndarray, block_expert: jnp.ndarray,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, H), xs.dtype),
-        interpret=interpret,
+        interpret=interpret, name="ragged_expert_matmul",
     )(*args)
 
 
-def _combine_kernel(rows_ref, w_ref, eo_ref, o_ref, *, block_t: int,
-                    max_k: int):
-    i = pl.program_id(0)
-    wblk = w_ref[...]                              # (bt, max_k)
-    for r in range(block_t):
-        acc = jnp.zeros((1, o_ref.shape[-1]), jnp.float32)
-        for j in range(max_k):
-            row = rows_ref[(i * block_t + r) * max_k + j]
-            er = pl.load(eo_ref, (pl.ds(row, 1), slice(None)))
-            acc = acc + er.astype(jnp.float32) * wblk[r, j]
-        o_ref[r, :] = acc[0].astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
-def ragged_combine(eo: jnp.ndarray, rows: jnp.ndarray, wrank: jnp.ndarray,
-                   *, block_t: int = BLOCK_M, interpret: bool = True):
+def ragged_combine(eo: jnp.ndarray, rows: jnp.ndarray,
+                   wrank: jnp.ndarray) -> jnp.ndarray:
     """eo: (N, D); rows: (T, max_k) int32; wrank: (T, max_k) ->
-    out (T, D) with ``out[t] = sum_j wrank[t, j] * eo[rows[t, j]]``.
-    A pure gather per token — no scatter, no cross-token accumulation."""
-    T, max_k = rows.shape
-    D = eo.shape[-1]
-    bt = min(block_t, T)
-    while T % bt:
-        bt -= 1
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(T // bt,),
-        in_specs=[
-            pl.BlockSpec((bt, max_k), lambda i, r: (i, 0)),
-            pl.BlockSpec(eo.shape, lambda i, r: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bt, D), lambda i, r: (i, 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_combine_kernel, block_t=bt, max_k=max_k),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, D), eo.dtype),
-        interpret=interpret,
-    )(rows.reshape(-1), wrank, eo)
+    out (T, D) with ``out[t] = sum_j wrank[t, j] * eo[rows[t, j]]`` — a
+    per-token gather (ranks past the token's budget carry weight 0 and
+    point at row 0), summed in fp32 and cast once."""
+    g = eo[rows].astype(jnp.float32)                   # (T, max_k, D)
+    out = (g * wrank[..., None].astype(jnp.float32)).sum(axis=1)
+    return out.astype(eo.dtype)

@@ -77,14 +77,6 @@ def topk_router_ref(logits: jnp.ndarray, k: int):
     return weights, mask, mask.sum(axis=0)
 
 
-def ragged_gather_ref(x: jnp.ndarray, src: jnp.ndarray,
-                      valid: jnp.ndarray) -> jnp.ndarray:
-    """Oracle for the ragged dispatch gather: x (T, D); src, valid (N,)
-    int32 -> (N, D) with ``out[i] = x[src[i]] * valid[i]`` (padding rows
-    land zero)."""
-    return x[src] * valid.astype(x.dtype)[:, None]
-
-
 def ragged_expert_matmul_ref(xs: jnp.ndarray, block_expert: jnp.ndarray,
                              w: jnp.ndarray, a: jnp.ndarray = None,
                              b: jnp.ndarray = None,
@@ -105,17 +97,6 @@ def ragged_expert_matmul_ref(xs: jnp.ndarray, block_expert: jnp.ndarray,
         y = y + jnp.einsum("bmr,brh->bmh", xa,
                            b[block_expert].astype(f32)) * scale
     return y.reshape(N, -1).astype(xs.dtype)
-
-
-def ragged_combine_ref(eo: jnp.ndarray, rows: jnp.ndarray,
-                       wrank: jnp.ndarray) -> jnp.ndarray:
-    """Oracle for the ragged combine: eo (N, D); rows (T, max_k) int32;
-    wrank (T, max_k) -> (T, D), ``out[t] = sum_j wrank[t,j] *
-    eo[rows[t,j]]`` — a per-token gather (ranks past the token's budget
-    carry weight 0 and point at row 0)."""
-    g = eo[rows].astype(jnp.float32)                   # (T, max_k, D)
-    out = (g * wrank[..., None].astype(jnp.float32)).sum(axis=1)
-    return out.astype(eo.dtype)
 
 
 def adaptive_topk_router_ref(logits: jnp.ndarray, k_tok: jnp.ndarray,

@@ -84,6 +84,6 @@ def topk_router(logits: jnp.ndarray, k: int, *, block_t: int = 1024,
             jax.ShapeDtypeStruct((T, E), jnp.float32),
             jax.ShapeDtypeStruct((1, E), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="topk_router",
     )(logits)
     return weights, mask, counts[0]

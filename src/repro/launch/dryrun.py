@@ -56,8 +56,9 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool = False,
     variant = variant_for(arch, shape_name)
     cfg = get_config(arch, variant)
     if mesh_shape is not None:
-        from .mesh import make_mesh_compat
-        mesh = make_mesh_compat(tuple(mesh_shape), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh(tuple(mesh_shape), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     mesh_name = "x".join(str(s) for s in mesh.devices.shape)

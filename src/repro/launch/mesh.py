@@ -19,35 +19,21 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-
-
-def make_mesh_compat(shape: Tuple[int, ...],
-                     axes: Tuple[str, ...]) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` across the supported jax version range.
-
-    ``jax.sharding.AxisType`` (and ``make_mesh``'s ``axis_types`` kwarg)
-    only exist in newer jax releases; older builds (e.g. 0.4.37) default
-    every axis to the same Auto semantics, so the fallback simply omits
-    the kwarg.  All mesh construction in the repo funnels through here so
-    the version gate lives in exactly one place.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh() -> jax.sharding.Mesh:
     """Single-device mesh with the production axis names — lets the same
     pjit'd step functions run on CPU for tests/examples."""
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def batch_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

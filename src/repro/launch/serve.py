@@ -33,6 +33,7 @@ from ..obs import MetricsRegistry, Tracer
 from ..serving import (ServingEngine, SpeculativeConfig, WorkloadConfig,
                        make_trace)
 from . import steps as steps_lib
+from .compile_cache import use_compile_cache
 from .mesh import make_production_mesh
 
 
@@ -179,6 +180,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main() -> None:
+    use_compile_cache()
     args = build_parser().parse_args()
 
     if not args.local:

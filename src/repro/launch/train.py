@@ -32,6 +32,7 @@ import numpy as np
 from ..configs.base import INPUT_SHAPES, ShapeConfig, TrainConfig
 from ..configs.registry import get_config
 from . import steps as steps_lib
+from .compile_cache import use_compile_cache
 from .mesh import make_local_mesh, make_production_mesh
 
 
@@ -78,6 +79,7 @@ def run_federated(args) -> None:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmoe-1.3b-6.9b")
     ap.add_argument("--variant", default=None,

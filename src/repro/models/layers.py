@@ -77,25 +77,28 @@ def lora_dense(x: jnp.ndarray, w: jnp.ndarray,
     """y = x @ W (+ (x @ A) @ B * scale when a LoRA adapter is present).
 
     ``x``: (..., d_in); ``w``: (d_in, d_out); ``lp``: {"a": (d_in, r),
-    "b": (r, d_out)} or None.  The LoRA bypass is computed in the weight
-    dtype; correction is added unmerged (the federated protocol keeps
-    A/B separate so the server can aggregate them).
+    "b": (r, d_out)} or None.  The correction is added unmerged (the
+    federated protocol keeps A/B separate so the server can aggregate
+    them).
 
     On the pallas path (``kernels``, a KernelConfig) an adapter-carrying
     projection runs the fused ``repro.kernels.lora_matmul`` kernel over the
-    flattened token dim.
+    flattened token dim.  The reference path keeps the kernel's numerics
+    contract (kernels/ref.py): fp32 math, one cast at the end.
     """
-    if lp is not None:
-        from ..kernels import backend as kernel_backend
-        if kernel_backend.use_pallas(kernels):
-            xf = x.reshape(-1, x.shape[-1])
-            y = kernel_backend.lora_matmul(kernels, xf, w, lp["a"],
-                                           lp["b"], scale=scale)
-            return y.reshape(x.shape[:-1] + (w.shape[-1],))
-    y = x @ w
-    if lp is not None:
-        y = y + ((x @ lp["a"]) @ lp["b"]) * jnp.asarray(scale, y.dtype)
-    return y
+    if lp is None:
+        return x @ w
+    from ..kernels import backend as kernel_backend
+    if kernel_backend.use_pallas(kernels):
+        xf = x.reshape(-1, x.shape[-1])
+        y = kernel_backend.lora_matmul(kernels, xf, w, lp["a"],
+                                       lp["b"], scale=scale)
+        return y.reshape(x.shape[:-1] + (w.shape[-1],))
+    f32 = jnp.float32
+    xf = x.astype(f32)
+    y = xf @ w.astype(f32)
+    y = y + ((xf @ lp["a"].astype(f32)) @ lp["b"].astype(f32)) * scale
+    return y.astype(x.dtype)
 
 
 def lora_expert_einsum(x: jnp.ndarray, w: jnp.ndarray,
@@ -135,12 +138,14 @@ def lora_expert_einsum(x: jnp.ndarray, w: jnp.ndarray,
             y = kernel_backend.lora_matmul_experts(
                 kernels, xt, w, lp["a"], lp["b"], scale=scale)
             return jnp.swapaxes(y.reshape(E, G, C, -1), 0, 1)
-        y = jnp.einsum("geci,eio->geco", x, w, preferred_element_type=f32)
+        # operands upcast to fp32, as in kernels/ref.py (XLA:CPU has no
+        # bf16 x bf16 -> f32 dot for this batched 4-D contraction)
+        xf = x.astype(f32)
+        y = jnp.einsum("geci,eio->geco", xf, w.astype(f32))
         if lp is not None:
-            xa = jnp.einsum("geci,eir->gecr", x, lp["a"],
-                            preferred_element_type=f32)
-            y = y + jnp.einsum("gecr,ero->geco", xa, lp["b"],
-                               preferred_element_type=f32) * scale
+            xa = jnp.einsum("geci,eir->gecr", xf, lp["a"].astype(f32))
+            y = y + jnp.einsum("gecr,ero->geco", xa,
+                               lp["b"].astype(f32)) * scale
         return y.astype(x.dtype)
     y = jnp.einsum("eci,eio->eco", x, w, preferred_element_type=f32)
     return y.astype(x.dtype)

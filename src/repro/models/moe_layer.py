@@ -186,11 +186,12 @@ def _ragged_expert_ffn(p: dict, cfg, x2d: jnp.ndarray, weights, mask, *,
 
     ``x2d``: (T, D) flat tokens; ``weights``/``mask``: (T, E);
     ``budget``: static worst-case assignment count; ``max_k``: static
-    per-token selection cap.  Every op dispatches through the kernel
-    backend (Pallas forward + reference backward on the pallas path)."""
+    per-token selection cap.  The grouped matmul dispatches through the
+    kernel backend (Pallas forward + reference backward on the pallas
+    path); the gather and the combine are XLA gathers."""
     from ..kernels import ragged_dispatch as ragged_mod
     plan = ragged_mod.ragged_plan(mask, weights, budget=budget, max_k=max_k)
-    xs = kernel_backend.ragged_gather(cfg.kernels, x2d, plan.src, plan.valid)
+    xs = ragged_mod.ragged_gather(x2d, plan.src, plan.valid)
 
     def mm(inp, key):
         lp = lora.get(key)
@@ -203,8 +204,7 @@ def _ragged_expert_ffn(p: dict, cfg, x2d: jnp.ndarray, weights, mask, *,
     up = mm(xs, "w3")
     h = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
     eo = mm(h, "w2")
-    return kernel_backend.ragged_combine(cfg.kernels, eo, plan.rows,
-                                         plan.wrank)
+    return ragged_mod.ragged_combine(eo, plan.rows, plan.wrank)
 
 
 def apply_moe(p: dict, cfg, x: jnp.ndarray, *, k,

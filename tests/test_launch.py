@@ -169,3 +169,32 @@ def test_active_params_match_paper():
     p = F.count_params(cfg, k=8)
     assert abs(p["total"] - 6.9e9) / 6.9e9 < 0.10
     assert abs(p["active"] - 1.3e9) / 1.3e9 < 0.10
+
+
+# --------------------------------------------------------- compile cache
+
+@pytest.fixture
+def restore_cache_dir():
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_defaults_to_fixed_dir_in_checkout(monkeypatch,
+                                                         restore_cache_dir):
+    from repro.launch import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    got = compile_cache.use_compile_cache()
+    repo = compile_cache.CACHE_DIR.parent
+    assert got == str(repo / ".jax_cache")
+    assert (repo / "src" / "repro" / "launch" / "compile_cache.py").is_file()
+    assert jax.config.jax_compilation_cache_dir == got
+
+
+def test_compile_cache_follows_env_and_sets_nothing(monkeypatch, tmp_path,
+                                                    restore_cache_dir):
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
