@@ -278,6 +278,7 @@ def main() -> None:
         print(f"  {key}: {val:.2f}" if isinstance(val, float)
               else f"  {key}: {val}")
     if tracer is not None:
+        tracer.close()
         print(f"trace: {tracer.dump(args.trace_out)} "
               f"({len(tracer.events)} events — open in Perfetto)")
     if registry is not None:

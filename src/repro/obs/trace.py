@@ -3,35 +3,110 @@
 Design points:
 
 * **Near-zero cost when disabled.** The engine holds ``NULL_TRACER`` by
-  default; every call site guards with ``if tracer.enabled`` (one
-  attribute load) so the disabled path does no formatting, no clock
-  reads, no allocation.
+  default; its ``span`` returns one shared null context and every other
+  call site guards with ``if tracer.enabled`` (one attribute load), so
+  the disabled path does no formatting, no clock reads, no allocation.
+* **One clock with the profiler.** A live :meth:`Tracer.span` is also a
+  ``jax.profiler.TraceAnnotation`` (its args become the event's stats):
+  free while no profiler session runs, and inside a session it lands in
+  the ``.xplane.pb`` host plane on the device trace's clock, so an idle
+  gap of the chip can be put down to the span the host was in.
 * **Flight recorder.** Events live in a bounded ``deque(maxlen=ring)``
   — the newest ``ring`` events double as the crash ring buffer. The
   engine calls :meth:`Tracer.flight_dump` from its exception path so a
   stuck or crashing run leaves a postmortem trace on disk.
-* **Two clocks.** Callers either pass explicit timestamps in *anchored
-  seconds* (the serving engine passes its own engine-relative clock
-  after ``anchor(0.0)``) or use the :meth:`span` context manager, which
-  reads ``perf_counter`` and lazily anchors at the first event (the
-  federated server path).
+* **One anchor.** Tracer time is ``perf_counter`` seconds since the
+  first reading the tracer saw, for its whole life: a tracer shared by
+  several engine runs or federated rounds writes them one after
+  another.  Spans known only afterwards (the per-request lifecycle,
+  whose spans overlap across requests) go through :meth:`complete` with
+  explicit tracer-time timestamps.
+* **Garbage collection.** An enabled tracer records each collection of
+  Python's garbage collector as a ``gc`` span (pid 1, tid 1) until
+  :meth:`Tracer.close`.  A collection can start at any allocation, so
+  read ``events`` after ``close()`` or through ``list(tracer.events)``:
+  a loop that allocates while it walks the live ring can see it grow.
 
-Track convention (pid/tid): pid 1 = the engine loop (tid 0), pid 2 =
-one thread per request rid, pid 3 = federated rounds. Metadata events
-(``ph: "M"``) name the tracks; they are kept out of the ring so names
-survive arbitrarily long runs.
+Track convention (pid/tid): pid 1 = the engine loop (tid 0) and the
+garbage collector (tid 1), pid 2 = one thread per request rid, pid 3 =
+federated rounds. Metadata events (``ph: "M"``) name the tracks; they
+are kept out of the ring so names survive arbitrarily long runs.
 """
 from __future__ import annotations
 
+import gc
 import json
 import time
+import weakref
 from collections import deque
-from contextlib import contextmanager
-from typing import Dict, Iterator, List, Optional, Tuple
+from functools import partial
+from typing import Dict, List, Optional, Tuple
 
 PID_ENGINE = 1
 PID_REQUESTS = 2
 PID_FEDERATED = 3
+TID_GC = 1
+
+
+class _Span:
+    """A live span: a profiler annotation, and a ring event at exit."""
+
+    __slots__ = ("tr", "name", "pid", "tid", "cat", "args", "ann", "t0")
+
+    def __init__(self, tr: "Tracer", name: str, pid: int, tid: int,
+                 cat: str, args: Optional[dict]) -> None:
+        self.tr, self.name, self.pid, self.tid = tr, name, pid, tid
+        self.cat, self.args = cat, dict(args) if args else {}
+
+    # Each clock reading sits next to the annotation's own (it starts on
+    # construction and stops in __exit__) with no allocation between, so
+    # a garbage collection cannot fall inside one duration and not the
+    # other.
+    def __enter__(self) -> "_Span":
+        self.ann = self.tr._annotation(self.name, **self.args)
+        self.t0 = self.tr.now()
+        return self
+
+    def set(self, **args) -> None:
+        """Args known only once the span's work is done."""
+        self.args.update(args)
+        self.ann.set_metadata(**args)
+
+    def __exit__(self, *exc) -> None:
+        self.ann.__exit__(*exc)
+        t1 = self.tr.now()
+        self.tr.complete(self.name, self.t0, t1, pid=self.pid, tid=self.tid,
+                         cat=self.cat, args=self.args)
+
+
+class _NullSpan:
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def set(self, **args) -> None:
+        pass
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+
+def _on_gc(ref, phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook; holds its tracer weakly."""
+    tr = ref()
+    if tr is None:
+        return
+    if phase == "start":
+        tr._gc_span = _Span(tr, "gc", PID_ENGINE, TID_GC, "gc",
+                            {"generation": info["generation"]}).__enter__()
+    elif tr._gc_span is not None:
+        tr._gc_span.set(collected=info["collected"])
+        tr._gc_span.__exit__(None, None, None)
+        tr._gc_span = None
 
 
 class Tracer:
@@ -39,25 +114,36 @@ class Tracer:
 
     def __init__(self, ring: int = 65536,
                  flight_path: Optional[str] = None) -> None:
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self.events: deque = deque(maxlen=int(ring))
         self.dropped = 0          # events pushed out of the ring
         self.flight_path = flight_path
         self._meta: Dict[Tuple[int, Optional[int]], dict] = {}
-        self._t0: Optional[float] = None   # perf_counter at anchored 0
+        self._t0: Optional[float] = None   # perf_counter at tracer time 0
+        self._gc_span: Optional[_Span] = None
+        hook = partial(_on_gc, weakref.ref(self))
+        gc.callbacks.append(hook)
+        self._unhook = weakref.finalize(self, gc.callbacks.remove, hook)
+        self.thread_name(PID_ENGINE, TID_GC, "python gc")
+
+    def close(self) -> None:
+        """Stop recording garbage collections (idempotent; also done when
+        the tracer itself is collected)."""
+        self._unhook()
 
     # -- clock ------------------------------------------------------------
-    def anchor(self, now_s: float = 0.0) -> None:
-        """Declare that ``perf_counter()`` *right now* corresponds to
-        anchored time ``now_s``. The engine anchors 0.0 at run start and
-        then passes its own relative timestamps."""
-        self._t0 = time.perf_counter() - now_s
+    def at(self, perf_s: float) -> float:
+        """Tracer time of a ``perf_counter`` reading; the first reading
+        the tracer sees is its 0."""
+        if self._t0 is None:
+            self._t0 = perf_s
+        return perf_s - self._t0
 
     def now(self) -> float:
-        if self._t0 is None:
-            self.anchor(0.0)
-        return time.perf_counter() - self._t0
+        return self.at(time.perf_counter())
 
-    # -- event emission (timestamps in anchored seconds) ------------------
+    # -- event emission (timestamps in tracer seconds) --------------------
     def _push(self, ev: dict) -> None:
         if len(self.events) == self.events.maxlen:
             self.dropped += 1
@@ -95,17 +181,13 @@ class Tracer:
                     "pid": pid, "tid": tid,
                     "args": {k: float(v) for k, v in values.items()}})
 
-    @contextmanager
     def span(self, name: str, *, pid: int = PID_ENGINE, tid: int = 0,
-             cat: str = "", args: Optional[dict] = None) -> Iterator[None]:
-        """Wall-clock span using the tracer's own (lazily anchored)
-        clock — the federated-server idiom."""
-        t0 = self.now()
-        try:
-            yield
-        finally:
-            self.complete(name, t0, self.now(), pid=pid, tid=tid, cat=cat,
-                          args=args)
+             cat: str = "", args: Optional[dict] = None) -> _Span:
+        """A live span, opened and closed as a context manager: a
+        ``jax.profiler.TraceAnnotation`` while open, a ``ph: "X"`` event
+        in the ring once closed.  ``set(**args)`` on the entered span adds
+        args known only at its end."""
+        return _Span(self, name, pid, tid, cat, args)
 
     # -- track naming -----------------------------------------------------
     def process_name(self, pid: int, name: str) -> None:
@@ -139,16 +221,14 @@ class Tracer:
 
 
 class _NullTracer:
-    """Disabled tracer: every method is a no-op. Hot paths additionally
-    guard on ``enabled`` so arguments are never even built."""
+    """Disabled tracer: every method is a no-op and ``span`` returns one
+    shared null context. Hot paths additionally guard on ``enabled`` so
+    arguments are never even built."""
 
     enabled = False
     events: deque = deque(maxlen=0)
     dropped = 0
     flight_path = None
-
-    def anchor(self, now_s: float = 0.0) -> None:
-        pass
 
     def now(self) -> float:
         return 0.0
@@ -162,9 +242,11 @@ class _NullTracer:
     def counter(self, *a, **kw) -> None:
         pass
 
-    @contextmanager
-    def span(self, *a, **kw) -> Iterator[None]:
-        yield
+    def span(self, *a, **kw) -> _NullSpan:
+        return _NULL_SPAN
+
+    def close(self) -> None:
+        pass
 
     def process_name(self, *a, **kw) -> None:
         pass

@@ -141,9 +141,11 @@ class _ActiveSlot:
     # times this request was swapped out mid-decode; capped by the
     # engine's max_preemptions so repeated preemption cannot livelock
     preemptions: int = 0
-    # engine time of the most recent swap-out (tracer: the swapped_out
-    # span runs from here to the swap_in that resumes the request)
+    # tracer time of the most recent swap-out (the swapped_out span runs
+    # from here to the swap_in that resumes the request)
     swap_t: float = 0.0
+    # engine time at which each token in ``tokens`` was drawn
+    token_t: List[float] = field(default_factory=list)
 
 
 def _pct_ms(xs: Sequence[float], q: float) -> Optional[float]:
@@ -330,9 +332,12 @@ class ServingEngine:
     Observability knobs (repro.obs; docs/observability.md) — all
     opt-in-pay, the defaults cost one attribute check per event site:
     ``tracer`` (a :class:`repro.obs.Tracer`) records request-lifecycle
-    spans (queued/prefill/decode + swap instants per rid) and
-    engine-loop spans (admit/prefill/decode_step), exports Chrome
-    trace-event JSON, and is flight-dumped if ``run()`` raises;
+    spans (queued/prefill/decode + swap instants per rid) and live
+    engine-loop spans (``engine.iteration`` > ``engine.admit`` >
+    ``engine.prefill``, ``engine.decode``, ``engine.sample``, and
+    ``engine.draft``/``engine.verify`` under speculation) that also land
+    in a running ``jax.profiler`` trace, exports Chrome trace-event
+    JSON, and is flight-dumped if ``run()`` raises;
     ``metrics`` (a :class:`repro.obs.MetricsRegistry`) receives engine
     counters and registers the pool and scheduler as snapshot-time
     sources; ``expert_telemetry=True`` (MoE, non-speculative) compiles
@@ -475,6 +480,10 @@ class ServingEngine:
             self._ctr_tokens = metrics.counter("serving.gen_tokens")
             self._ctr_admitted = metrics.counter("serving.admitted")
             self._ctr_preempt = metrics.counter("serving.preemptions")
+            self._ctr_draws = metrics.counter("serving.sample.draws")
+            self._ctr_prefills = metrics.counter("serving.prefill.calls")
+            self._ctr_prefill_rows = metrics.counter("serving.prefill.rows")
+            self._ctr_steps = metrics.counter("serving.decode.steps")
         if self._tracer.enabled:
             self._tracer.process_name(PID_ENGINE, "serving-engine")
             self._tracer.thread_name(PID_ENGINE, 0, "engine loop")
@@ -693,28 +702,25 @@ class ServingEngine:
         """One admission round: a normal packing pass, then — with
         preemption on — swap out lenient-deadline victims while a waiter
         is past its TTFT deadline and another pass can seat it."""
-        t0 = self._now()
-        n = self._admit_pass(report)
-        if self._preemption:
-            for _ in range(self.num_slots):
-                victim = self._pick_victim()
-                if victim is None:
-                    break
-                self._preempt(victim, report)
-                got = self._admit_pass(report)
-                n += got
-                if got == 0:
-                    # the freed blocks alone didn't seat the waiter
-                    # (quota-bound, or it needs more than one victim);
-                    # stop rather than strip the pool in one round —
-                    # the next engine iteration tries again
-                    break
-        if n:
-            if self._tracer.enabled:
-                self._tracer.complete("admit", t0, self._now(), cat="engine",
-                                      args={"admitted": n})
-            if self._metrics is not None:
-                self._ctr_admitted.inc(n)
+        with self._tracer.span("engine.admit", cat="engine") as sp:
+            n = self._admit_pass(report)
+            if self._preemption:
+                for _ in range(self.num_slots):
+                    victim = self._pick_victim()
+                    if victim is None:
+                        break
+                    self._preempt(victim, report)
+                    got = self._admit_pass(report)
+                    n += got
+                    if got == 0:
+                        # the freed blocks alone didn't seat the waiter
+                        # (quota-bound, or it needs more than one victim);
+                        # stop rather than strip the pool in one round —
+                        # the next engine iteration tries again
+                        break
+            sp.set(admitted=n)
+        if n and self._metrics is not None:
+            self._ctr_admitted.inc(n)
         return n
 
     def _pick_victim(self) -> Optional[int]:
@@ -770,7 +776,7 @@ class ServingEngine:
         self.scheduler.add(a.req)
         report.preemptions += 1
         if self._tracer.enabled:
-            a.swap_t = self._now()
+            a.swap_t = self._tracer.now()
             self._tracer.instant("swap_out", a.swap_t, pid=PID_REQUESTS,
                                  tid=a.req.rid, cat="preempt",
                                  args={"slot": slot})
@@ -859,7 +865,7 @@ class ServingEngine:
                 self._active[slot] = a
                 self._last_tok[slot, 0] = last
                 if self._tracer.enabled:
-                    now = self._now()
+                    now = self._tracer.now()
                     self._tracer.complete(
                         "swapped_out", a.swap_t, now, pid=PID_REQUESTS,
                         tid=req.rid, cat="preempt")
@@ -893,90 +899,100 @@ class ServingEngine:
                     ("full", req.prompt_len, self.slot_k[slot]),
                     []).append((req, slot, 0, 0))
 
+        tr = self._tracer
         for (kind, width, kk), items in groups.items():
             nb = len(items)
             bucket = _bucket(nb)
             admitted = self._now()
-            real = jnp.asarray(np.arange(bucket) < nb, jnp.float32)
-            if kind == "suffix":
-                # width == the group's suffix bucket; prompt lengths may
-                # differ within it.  Pad rows: empty table, prefix 0,
-                # suffix 1 — their gathers are fully masked (pos 0) and
-                # their outputs discarded.
-                toks = np.zeros((bucket, width), np.int32)
-                pref = np.zeros((bucket,), np.int32)
-                suf = np.ones((bucket,), np.int32)
-                # page-span bucket: only the blocks covering the group's
-                # deepest live prefix + the suffix are gathered in-graph
-                # (pow-2 to bound compile variants) — a short suffix on
-                # a short prefix must not pay a full-slot gather
-                bs = self.pool.block_size
-                span_b = min(_bucket(-(-(max(
-                    st for _, _, st, _ in items) + width) // bs)),
-                    self.pool.blocks_per_slot)
-                tbl = np.zeros((bucket, span_b), np.int32)
-                for j, (req, slot, sstart, _cov) in enumerate(items):
-                    n = req.prompt_len - sstart
-                    toks[j, :n] = np.asarray(req.prompt[sstart:], np.int32)
-                    pref[j], suf[j] = sstart, n
-                    tbl[j] = self.pool.block_table[slot][:span_b]
-                logits, piece = self._suffix_prefill_fn(
-                    self.params, self._prefill_trainable(kk),
-                    self.pool.cache, jnp.asarray(toks), jnp.asarray(tbl),
-                    jnp.asarray(pref), jnp.asarray(suf), real, k=kk)
-                logits_np = np.asarray(logits)      # blocks until ready
-                self.pool.write(
-                    [s for _, s, _, _ in items], piece,
-                    [r.prompt_len for r, _, _, _ in items],
-                    starts=[cov for _, _, _, cov in items],
-                    piece_col0=[st for _, _, st, _ in items])
-                report.prefill_tokens += int(suf[:nb].sum())
-                targs = {"batch": nb, "bucket": bucket,
-                         "suffix_bucket": width}
-            else:
-                prompts = np.stack([r.prompt for r, _, _, _ in items]
-                                   + [items[0][0].prompt] * (bucket - nb))
-                logits, cache = self._prefill_fn(
-                    self.params, self._prefill_trainable(kk),
-                    jnp.asarray(prompts), real, k=kk)
-                logits_np = np.asarray(logits)      # blocks until ready
-                if self._use_suffix:
-                    # match/attach/alloc already ran at assignment time:
-                    # scatter past the matched span only (a same-batch
-                    # duplicate recomputes its whole prompt — pending
-                    # pages aren't readable in-graph — but must not
-                    # rewrite the shared blocks it attached)
+            targs = {"batch": nb, "bucket": bucket,
+                     "suffix_bucket" if kind == "suffix" else "prompt_len":
+                     width, "k": kk if kk is not None else 0}
+            with tr.span("engine.prefill", cat="engine", args=targs):
+                real = jnp.asarray(np.arange(bucket) < nb, jnp.float32)
+                if kind == "suffix":
+                    # width == the group's suffix bucket; prompt lengths
+                    # may differ within it.  Pad rows: empty table, prefix
+                    # 0, suffix 1 — their gathers are fully masked (pos 0)
+                    # and their outputs discarded.
+                    toks = np.zeros((bucket, width), np.int32)
+                    pref = np.zeros((bucket,), np.int32)
+                    suf = np.ones((bucket,), np.int32)
+                    # page-span bucket: only the blocks covering the
+                    # group's deepest live prefix + the suffix are gathered
+                    # in-graph (pow-2 to bound compile variants) — a short
+                    # suffix on a short prefix must not pay a full-slot
+                    # gather
+                    bs = self.pool.block_size
+                    span_b = min(_bucket(-(-(max(
+                        st for _, _, st, _ in items) + width) // bs)),
+                        self.pool.blocks_per_slot)
+                    tbl = np.zeros((bucket, span_b), np.int32)
+                    for j, (req, slot, sstart, _cov) in enumerate(items):
+                        n = req.prompt_len - sstart
+                        toks[j, :n] = np.asarray(req.prompt[sstart:],
+                                                 np.int32)
+                        pref[j], suf[j] = sstart, n
+                        tbl[j] = self.pool.block_table[slot][:span_b]
+                    logits, piece = self._suffix_prefill_fn(
+                        self.params, self._prefill_trainable(kk),
+                        self.pool.cache, jnp.asarray(toks),
+                        jnp.asarray(tbl), jnp.asarray(pref),
+                        jnp.asarray(suf), real, k=kk)
+                    logits_np = np.asarray(logits)  # blocks until ready
                     self.pool.write(
-                        [s for _, s, _, _ in items], cache, [width] * nb,
-                        starts=[cov for _, _, _, cov in items])
+                        [s for _, s, _, _ in items], piece,
+                        [r.prompt_len for r, _, _, _ in items],
+                        starts=[cov for _, _, _, cov in items],
+                        piece_col0=[st for _, _, st, _ in items])
+                    report.prefill_tokens += int(suf[:nb].sum())
                 else:
-                    self.pool.write(
-                        [s for _, s, _, _ in items], cache, [width] * nb,
-                        tokens=[r.prompt for r, _, _, _ in items],
-                        salt=_tier_salt(kk))
-                report.prefill_tokens += nb * width
-                targs = {"batch": nb, "bucket": bucket,
-                         "prompt_len": width}
+                    prompts = np.stack(
+                        [r.prompt for r, _, _, _ in items]
+                        + [items[0][0].prompt] * (bucket - nb))
+                    logits, cache = self._prefill_fn(
+                        self.params, self._prefill_trainable(kk),
+                        jnp.asarray(prompts), real, k=kk)
+                    logits_np = np.asarray(logits)  # blocks until ready
+                    if self._use_suffix:
+                        # match/attach/alloc already ran at assignment
+                        # time: scatter past the matched span only (a
+                        # same-batch duplicate recomputes its whole prompt
+                        # — pending pages aren't readable in-graph — but
+                        # must not rewrite the shared blocks it attached)
+                        self.pool.write(
+                            [s for _, s, _, _ in items], cache,
+                            [width] * nb,
+                            starts=[cov for _, _, _, cov in items])
+                    else:
+                        self.pool.write(
+                            [s for _, s, _, _ in items], cache,
+                            [width] * nb,
+                            tokens=[r.prompt for r, _, _, _ in items],
+                            salt=_tier_salt(kk))
+                    report.prefill_tokens += nb * width
             tft = self._now()
             report.prefill_s.append(tft - admitted)
             report.prefill_hist.observe((tft - admitted) * 1e3)
-            if self._tracer.enabled:
-                targs["k"] = kk if kk is not None else 0
-                self._tracer.complete("prefill", admitted, tft, cat="engine",
-                                      args=targs)
+            if self._metrics is not None:
+                self._ctr_prefills.inc()
+                self._ctr_prefill_rows.inc(nb)
+                self._ctr_draws.inc(nb)
 
-            for j, (req, slot, _st, _cov) in enumerate(items):
-                max_new = self._max_new(req)
-                a = _ActiveSlot(
-                    req=req, tokens=[], nll=0.0, admitted=admitted,
-                    first_token=tft, max_new=max_new)
-                self._active[slot] = a
-                tok, nll = self._pick(logits_np[j], a)
-                a.tokens.append(tok)
-                a.nll += nll
-                self._last_tok[slot, 0] = tok
-                if len(a.tokens) >= max_new or self.pool.slot_full(slot):
-                    self._finish(slot, report)
+            with tr.span("engine.sample", cat="engine",
+                         args={"draws": nb, "phase": "prefill"}):
+                for j, (req, slot, _st, _cov) in enumerate(items):
+                    max_new = self._max_new(req)
+                    a = _ActiveSlot(
+                        req=req, tokens=[], nll=0.0, admitted=admitted,
+                        first_token=tft, max_new=max_new)
+                    self._active[slot] = a
+                    tok, nll = self._pick(logits_np[j], a)
+                    a.tokens.append(tok)
+                    a.token_t.append(self._now())
+                    a.nll += nll
+                    self._last_tok[slot, 0] = tok
+                    if len(a.tokens) >= max_new or self.pool.slot_full(slot):
+                        self._finish(slot, report)
         return len(assignments)
 
     # --------------------------------------------------------------- sampling
@@ -1016,42 +1032,47 @@ class ServingEngine:
     # ----------------------------------------------------------------- decode
     def _decode_once(self, report: ServingReport) -> None:
         t_start = time.perf_counter()
-        active = [s for s, a in enumerate(self._active) if a is not None]
-        active_mask = jnp.asarray(
-            [a is not None for a in self._active], jnp.float32)
-        extra = ()
-        if self.paged:
-            # allocate each active row's next write block (guaranteed to
-            # succeed: covered by the reservation made at admit)
-            self.pool.prepare_decode(active)
-            extra = (self.pool.tables(),)
-        out = self._decode_fn(
-            self.params, self._decode_trainable, self.pool.cache,
-            jnp.asarray(self._last_tok), self.pool.positions(), active_mask,
-            *extra)
-        logits, new_cache = out[0], out[1]
-        logits_np = np.asarray(logits)              # blocks until ready
-        self.pool.cache = new_cache
-        dt = time.perf_counter() - t_start
+        tr = self._tracer
+        with tr.span("engine.decode", cat="engine") as sp:
+            active = [s for s, a in enumerate(self._active) if a is not None]
+            sp.set(active=len(active))
+            active_mask = jnp.asarray(
+                [a is not None for a in self._active], jnp.float32)
+            extra = ()
+            if self.paged:
+                # allocate each active row's next write block (guaranteed
+                # to succeed: covered by the reservation made at admit)
+                self.pool.prepare_decode(active)
+                extra = (self.pool.tables(),)
+            out = self._decode_fn(
+                self.params, self._decode_trainable, self.pool.cache,
+                jnp.asarray(self._last_tok), self.pool.positions(),
+                active_mask, *extra)
+            logits, new_cache = out[0], out[1]
+            logits_np = np.asarray(logits)          # blocks until ready
+            self.pool.cache = new_cache
+            dt = time.perf_counter() - t_start
         report.decode_step_s.append(dt)
         report.decode_hist.observe(dt * 1e3)
+        if self._metrics is not None:
+            self._ctr_steps.inc()
+            self._ctr_draws.inc(len(active))
         if self._expert_telemetry:
             self._expert_tracker.observe_step(
                 {p: np.asarray(c) for p, c in out[2].items()})
-        if self._tracer.enabled:
-            end = self._now()
-            self._tracer.complete("decode_step", end - dt, end, cat="engine",
-                                  args={"active": len(active)})
 
         self.pool.advance(active)
-        for slot in active:
-            a = self._active[slot]
-            tok, nll = self._pick(logits_np[slot], a)
-            a.tokens.append(tok)
-            a.nll += nll
-            self._last_tok[slot, 0] = tok
-            if len(a.tokens) >= a.max_new or self.pool.slot_full(slot):
-                self._finish(slot, report)
+        with tr.span("engine.sample", cat="engine",
+                     args={"draws": len(active), "phase": "decode"}):
+            for slot in active:
+                a = self._active[slot]
+                tok, nll = self._pick(logits_np[slot], a)
+                a.tokens.append(tok)
+                a.token_t.append(self._now())
+                a.nll += nll
+                self._last_tok[slot, 0] = tok
+                if len(a.tokens) >= a.max_new or self.pool.slot_full(slot):
+                    self._finish(slot, report)
 
     def _finish(self, slot: int, report: ServingReport) -> None:
         a = self._active[slot]
@@ -1062,26 +1083,28 @@ class ServingEngine:
             admitted=a.admitted, first_token=a.first_token,
             finished=self._now(), nll_sum=a.nll,
             truncated=len(a.tokens) < a.max_new,
-            preemptions=a.preemptions)
+            preemptions=a.preemptions,
+            token_t=np.asarray(a.token_t, np.float64))
         report.completions.append(c)
         if self._tracer.enabled:
             # the request's lifecycle track, emitted retrospectively from
-            # the completion's timestamps: an enclosing span plus the
-            # queued → prefill → decode phases (swap events were emitted
-            # live as the preemptions happened)
-            tr = self._tracer
+            # the completion's timestamps (engine time, shifted by the
+            # run's start on the tracer's clock): an enclosing span plus
+            # the queued → prefill → decode phases (swap events were
+            # emitted live as the preemptions happened)
+            tr, off = self._tracer, self._trace_off
             tid = c.rid
             tr.thread_name(PID_REQUESTS, tid, f"req {tid}")
             args = {"rid": c.rid, "k": c.k, "prompt_len": c.prompt_len,
                     "gen_tokens": c.n_generated,
                     "preemptions": c.preemptions}
-            tr.complete("request", c.arrival, c.finished, pid=PID_REQUESTS,
-                        tid=tid, cat="request", args=args)
-            tr.complete("queued", c.arrival, c.admitted, pid=PID_REQUESTS,
-                        tid=tid, cat="request")
-            tr.complete("prefill", c.admitted, c.first_token,
+            tr.complete("request", c.arrival + off, c.finished + off,
+                        pid=PID_REQUESTS, tid=tid, cat="request", args=args)
+            tr.complete("queued", c.arrival + off, c.admitted + off,
                         pid=PID_REQUESTS, tid=tid, cat="request")
-            tr.complete("decode", c.first_token, c.finished,
+            tr.complete("prefill", c.admitted + off, c.first_token + off,
+                        pid=PID_REQUESTS, tid=tid, cat="request")
+            tr.complete("decode", c.first_token + off, c.finished + off,
                         pid=PID_REQUESTS, tid=tid, cat="request")
         if self._metrics is not None:
             self._ctr_completions.inc()
@@ -1147,37 +1170,39 @@ class ServingEngine:
             self._expert_tracker.reset()
         self._t0 = time.perf_counter()
         tr = self._tracer
-        if tr.enabled:
-            tr.anchor(0.0)           # tracer time == engine-relative time
+        # tracer time at the run's engine time 0: the tracer keeps one
+        # clock across runs, the engine's restarts with each
+        self._trace_off = tr.at(self._t0) if tr.enabled else 0.0
         steps = 0
         try:
             while pending or len(self.scheduler) or self.n_active:
-                now = self._now()
-                while pending and pending[0].arrival <= now:
-                    self.scheduler.add(pending.pop(0))
-                admitted = self._admit(report)
-                if tr.enabled:
-                    tr.counter("engine", self._now(),
-                               {"active_slots": self.n_active,
-                                "queue_depth": len(self.scheduler)})
-                if self.n_active:
-                    if self._spec is not None:
-                        self._spec.round(report)
-                    else:
-                        self._decode_once(report)
-                    steps += 1
-                    if max_steps is not None and steps >= max_steps:
-                        break
-                elif not admitted:
-                    if pending:              # idle until the next arrival
-                        time.sleep(max(0.0,
-                                       min(pending[0].arrival - self._now(),
-                                           0.01)))
-                    elif len(self.scheduler):
-                        stuck = [r.rid for r in self.scheduler.queue]
-                        raise RuntimeError(
-                            f"requests {stuck} match no slot tier "
-                            f"(slot_k={self.slot_k})")
+                with tr.span("engine.iteration", cat="engine") as it:
+                    now = self._now()
+                    while pending and pending[0].arrival <= now:
+                        self.scheduler.add(pending.pop(0))
+                    admitted = self._admit(report)
+                    if tr.enabled:
+                        load = {"active_slots": self.n_active,
+                                "queue_depth": len(self.scheduler)}
+                        tr.counter("engine", tr.now(), load)
+                        it.set(**load)
+                    if self.n_active:
+                        if self._spec is not None:
+                            self._spec.round(report)
+                        else:
+                            self._decode_once(report)
+                        steps += 1
+                        if max_steps is not None and steps >= max_steps:
+                            break
+                    elif not admitted:
+                        if pending:          # idle until the next arrival
+                            time.sleep(max(0.0, min(
+                                pending[0].arrival - self._now(), 0.01)))
+                        elif len(self.scheduler):
+                            stuck = [r.rid for r in self.scheduler.queue]
+                            raise RuntimeError(
+                                f"requests {stuck} match no slot tier "
+                                f"(slot_k={self.slot_k})")
         except Exception:
             # flight recorder: leave the last `ring` trace events on disk
             # for a postmortem of the stuck/crashed run, then re-raise

@@ -62,6 +62,10 @@ class Completion:
     nll_sum: float = 0.0               # teacher-forced NLL (forced mode)
     truncated: bool = False            # slot capacity hit before max_new
     preemptions: int = 0               # times swapped out mid-decode
+    # engine time at which each generated token was drawn (float64, one
+    # entry per token): first_token <= token_t[0], token_t[-1] <= finished
+    token_t: np.ndarray = field(
+        default_factory=lambda: np.zeros(0, np.float64))
 
     @property
     def ttft(self) -> float:

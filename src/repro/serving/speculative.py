@@ -296,64 +296,62 @@ class SpeculativeDecoder:
             self._open[s] = int(pos0[s])
 
         # ---- draft: one fused launch covering W cheap read-only steps ----
+        tr = eng._tracer
         t0 = time.perf_counter()
-        if eng.paged:
-            # the draft never writes pages — the tables are passed only
-            # for the one-shot prefix gather — but the VERIFY step writes
-            # positions pos0 .. pos0+W-1, so allocate every window
-            # position's block up front (covered by the admit-time
-            # reservation)
-            for _ in range(W):
-                pool.prepare_decode(active)
-                pool.advance(active)
-            extra = (pool.tables(),)
-        else:
-            for _ in range(W):
-                pool.advance(active)
-            extra = ()
-        qs, toks = self._draft_fn(
-            eng.params, self._draft_trainable, pool.cache,
-            jnp.asarray(first), jnp.asarray(pos0), *extra,
-            self._draw_keys(active, W))
-        d_toks = np.asarray(toks)                          # (W, B) — sync
+        with tr.span("engine.draft", cat="engine",
+                     args={"window": W, "active": len(active)}):
+            if eng.paged:
+                # the draft never writes pages — the tables are passed
+                # only for the one-shot prefix gather — but the VERIFY
+                # step writes positions pos0 .. pos0+W-1, so allocate
+                # every window position's block up front (covered by the
+                # admit-time reservation)
+                for _ in range(W):
+                    pool.prepare_decode(active)
+                    pool.advance(active)
+                extra = (pool.tables(),)
+            else:
+                for _ in range(W):
+                    pool.advance(active)
+                extra = ()
+            qs, toks = self._draft_fn(
+                eng.params, self._draft_trainable, pool.cache,
+                jnp.asarray(first), jnp.asarray(pos0), *extra,
+                self._draw_keys(active, W))
+            d_toks = np.asarray(toks)                      # (W, B) — sync
         dt = time.perf_counter() - t0
         report.draft_step_s.append(dt)
         report.draft_hist.observe(dt * 1e3)
-        if eng._tracer.enabled:
-            end = eng._now()
-            eng._tracer.complete("draft", end - dt, end, cat="engine",
-                                 args={"window": W, "active": len(active)})
 
         # ---- verify + reject: one full-k step over the W+1 window
         # tokens, then the vmapped rejection rule over all slots ----
         t0 = time.perf_counter()
-        extra = ()
-        if eng.paged:
-            pool.prepare_decode(active)                    # pos0 + W
-            extra = (pool.tables(),)
-        verify_in = np.concatenate([first, d_toks.T], axis=1)  # (B, W+1)
-        lv, cache = self._verify_fn(
-            eng.params, eng._decode_trainable, pool.cache,
-            jnp.asarray(verify_in), jnp.asarray(pos0), active_mask, *extra)
-        pool.cache = cache
-        out_toks, n_emit, n_acc = self._reject_fn(
-            self._reject_keys(active), jnp.asarray(d_toks.T),
-            jnp.moveaxis(qs, 0, 1), lv)
-        out_toks = np.asarray(out_toks)                    # (B, W+1) — sync
-        n_emit, n_acc = np.asarray(n_emit), np.asarray(n_acc)
+        with tr.span("engine.verify", cat="engine", args={"window": W}):
+            extra = ()
+            if eng.paged:
+                pool.prepare_decode(active)                # pos0 + W
+                extra = (pool.tables(),)
+            verify_in = np.concatenate([first, d_toks.T], axis=1)
+            lv, cache = self._verify_fn(
+                eng.params, eng._decode_trainable, pool.cache,
+                jnp.asarray(verify_in), jnp.asarray(pos0), active_mask,
+                *extra)
+            pool.cache = cache
+            out_toks, n_emit, n_acc = self._reject_fn(
+                self._reject_keys(active), jnp.asarray(d_toks.T),
+                jnp.moveaxis(qs, 0, 1), lv)
+            out_toks = np.asarray(out_toks)                # (B, W+1) — sync
+            n_emit, n_acc = np.asarray(n_emit), np.asarray(n_acc)
         dt = time.perf_counter() - t0
         report.verify_step_s.append(dt)
         report.verify_hist.observe(dt * 1e3)
-        if eng._tracer.enabled:
-            end = eng._now()
-            eng._tracer.complete("verify", end - dt, end, cat="engine",
-                                 args={"window": W})
 
         for s in active:
             a = eng._active[s]
             acc = int(n_acc[s])
             emitted = [int(t) for t in out_toks[s, :int(n_emit[s])]]
             a.tokens.extend(emitted)
+            a.token_t.extend([eng._now()] * len(emitted))
             eng._last_tok[s, 0] = emitted[-1]
             report.spec_drafted += W
             report.spec_accepted += acc

@@ -8,6 +8,8 @@ balanced swap_out/swap_in pairs under preemption); a 3-round federated
 run emits per-round activation-frequency drift with ``l1_drift`` None
 on the first round and finite after.
 """
+import gc
+import glob
 import json
 
 import jax
@@ -20,7 +22,7 @@ from repro.obs import (ActivationDriftTracker, Counter, ExpertLoadTracker,
                        Gauge, Histogram, MetricsRegistry, NULL_TRACER,
                        Tracer, entropy, exp_buckets, gini,
                        validate_chrome_trace)
-from repro.obs.trace import PID_ENGINE, PID_REQUESTS
+from repro.obs.trace import PID_ENGINE, PID_REQUESTS, TID_GC
 from repro.serving import Request, ServingEngine, SpeculativeConfig
 from repro.serving.engine import ServingReport
 
@@ -158,6 +160,35 @@ def test_flight_recorder_dump(tmp_path):
     assert kept == [f"e{i}" for i in range(12, 20)]   # newest 8 survive
 
 
+def test_null_tracer_span_is_one_shared_context():
+    a, b = NULL_TRACER.span("a"), NULL_TRACER.span("b", args={"x": 1})
+    assert a is b
+    with a as sp:
+        sp.set(y=2)
+    assert len(NULL_TRACER.events) == 0
+
+
+def test_gc_hook_records_collections_until_closed():
+    before = len(gc.callbacks)
+    tr = Tracer()
+    assert len(gc.callbacks) == before + 1
+    gc.collect()
+    evs = [e for e in tr.events if e["name"] == "gc"]
+    assert evs and evs[-1]["args"]["generation"] == 2
+    assert evs[-1]["args"]["collected"] >= 0
+    assert (evs[-1]["pid"], evs[-1]["tid"]) == (PID_ENGINE, TID_GC)
+    tr.close()
+    tr.close()                                 # idempotent
+    assert len(gc.callbacks) == before
+    n = len(tr.events)
+    gc.collect()
+    assert len(tr.events) == n
+    dropped = Tracer()                         # never closed: unhooked
+    del dropped                                # when it is collected
+    gc.collect()
+    assert len(gc.callbacks) == before
+
+
 # ==========================================================================
 # expert-load + drift primitives
 # ==========================================================================
@@ -233,10 +264,119 @@ def test_trace_request_lifecycle_spans(traced_run, tmp_path):
         assert {"request", "queued", "prefill", "decode"} <= by_rid[c.rid]
     engine_names = {e["name"] for e in trace["traceEvents"]
                     if e["pid"] == PID_ENGINE and e["ph"] == "X"}
-    assert {"admit", "prefill", "decode_step"} <= engine_names
+    assert {"engine.iteration", "engine.admit", "engine.prefill",
+            "engine.decode", "engine.sample"} <= engine_names
     p = tmp_path / "serve-trace.json"
     tracer.dump(str(p))                        # strict-JSON round trip
     assert validate_chrome_trace(json.loads(p.read_text())) == []
+
+
+def _reqs(n, new_tokens=6, plen=8):
+    return [Request(rid=i, prompt=RNG.integers(0, CFG.vocab_size, (plen,))
+                    .astype(np.int32),
+                    max_new_tokens=new_tokens, k=(2 if i % 2 == 0 else 1))
+            for i in range(n)]
+
+
+def _engine_spans(events):
+    return [e for e in events
+            if e["ph"] == "X" and e["name"].startswith("engine.")]
+
+
+def test_engine_spans_reach_profiler_trace(tmp_path):
+    """The engine's live spans land in a jax.profiler trace's host plane,
+    args as event stats, with the tracer's own durations."""
+    from jax.profiler import ProfileData
+    tracer = Tracer()
+    eng = ServingEngine(CFG, PARAMS, num_slots=4, slot_len=16,
+                        slot_k=(2, 2, 1, 1), tracer=tracer)
+    eng.run(_reqs(4))                          # compile outside the trace
+    tracer.events.clear()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.run(_reqs(6))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    prof = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("engine."):
+                        prof.setdefault(e.name, []).append(
+                            (e.start_ns, e.duration_ns / 1e3,
+                             dict(e.stats)))
+    tracer.close()
+    mem = {}
+    for e in _engine_spans(tracer.events):
+        mem.setdefault(e["name"], []).append((e["ts"], e["dur"],
+                                              e.get("args", {})))
+    assert set(prof) == set(mem) == {
+        "engine.iteration", "engine.admit", "engine.prefill",
+        "engine.decode", "engine.sample"}
+    for name, got in prof.items():
+        want = mem[name]
+        assert len(got) == len(want), name
+        for (_, dur_p, stats), (_, dur_m, args) in zip(sorted(got),
+                                                       sorted(want)):
+            assert abs(dur_p - dur_m) < 100.0, (name, dur_p, dur_m)
+            assert stats == args, name
+    phases = {a["phase"] for _, _, a in mem["engine.sample"]}
+    assert phases == {"decode", "prefill"}
+
+
+def test_tracer_shared_across_runs_keeps_one_clock():
+    tracer = Tracer()
+    eng = ServingEngine(CFG, PARAMS, num_slots=4, slot_len=16,
+                        slot_k=(2, 2, 1, 1), tracer=tracer)
+    eng.run(_reqs(4))
+    first = len(_engine_spans(tracer.events))
+    eng.run(_reqs(4))
+    tracer.close()
+    events = list(tracer.events)
+    its = sorted((e["ts"], e["ts"] + e["dur"])
+                 for e in events if e["name"] == "engine.iteration")
+    assert len(_engine_spans(events)) > first
+    for (_, end), (start, _) in zip(its, its[1:]):
+        assert start >= end
+    reqs = [e for e in events
+            if e["pid"] == PID_REQUESTS and e["name"] == "request"]
+    by_tid = {}
+    for e in reqs:
+        by_tid.setdefault(e["tid"], []).append((e["ts"], e["ts"] + e["dur"]))
+    for spans in by_tid.values():             # each rid once per run
+        assert len(spans) == 2 and spans[0][1] <= spans[1][0]
+    assert validate_chrome_trace(tracer.to_dict()) == []
+
+
+@pytest.mark.parametrize("spec", [None, SpeculativeConfig(window=3,
+                                                          draft_k=1)],
+                         ids=["decode", "speculative"])
+def test_completion_token_times(spec):
+    eng = ServingEngine(CFG, PARAMS, num_slots=4, slot_len=24,
+                        slot_k=(2, 2, 2, 2), speculative=spec)
+    rep = eng.run([Request(rid=i, prompt=RNG.integers(
+        0, CFG.vocab_size, (6,)).astype(np.int32), max_new_tokens=3 + i,
+        k=2) for i in range(6)])
+    for c in rep.completions:
+        t = c.token_t
+        assert t.dtype == np.float64 and t.shape == (c.n_generated,)
+        assert np.all(np.diff(t) >= 0)
+        assert c.first_token <= t[0] and t[-1] <= c.finished
+
+
+def test_engine_step_counters(traced_run):
+    _, _, reg, rep = traced_run
+    snap = reg.snapshot()
+    assert snap["serving.sample.draws"]["value"] == sum(
+        c.n_generated for c in rep.completions)
+    assert snap["serving.decode.steps"]["value"] == len(rep.decode_step_s)
+    assert snap["serving.prefill.calls"]["value"] == len(rep.prefill_s)
+    assert snap["serving.prefill.rows"]["value"] == len(rep.completions)
 
 
 def test_engine_registry_snapshot(traced_run):
